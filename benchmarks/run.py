@@ -26,6 +26,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _bench_fig6(args):
     from . import fig6_mdna
@@ -198,6 +200,7 @@ def main(argv=None):
     if unknown:
         ap.error(f"unknown bench(es): {sorted(unknown)}")
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, (fn, default_on) in BENCHES.items():
         if (only is None and default_on) or (only is not None

@@ -50,6 +50,13 @@ _ACTIVE_TMP: set = set()
 _ACTIVE_LOCK = threading.Lock()
 
 
+#: What restoring a missing, torn (truncated .npz) or mislabelled
+#: checkpoint raises — file-format errors only.  Restore paths that
+#: fall back to a fresh init catch exactly these, so a device or runtime
+#: fault during a restore surfaces instead of passing for a torn file.
+TORN_CHECKPOINT_ERRORS = (OSError, KeyError, ValueError, zipfile.BadZipFile)
+
+
 class CheckpointNotFoundError(FileNotFoundError):
     """A requested checkpoint step does not exist (never written, or
     already garbage-collected).  Subclasses FileNotFoundError so callers
@@ -257,8 +264,7 @@ def restore_elastic(ckpt_dir: str, step: int, template, init_fn,
             with np.load(path) as z:
                 chains.append(_unflatten_into(tmpl0, dict(z)))
             restored.append(i)
-        except (FileNotFoundError, KeyError, ValueError, OSError,
-                zipfile.BadZipFile):   # truncated .npz = torn write
+        except TORN_CHECKPOINT_ERRORS:
             if not missing_ok:
                 raise
             chains.append(init_fn(i))

@@ -140,6 +140,14 @@ class ExecutionPlan:
     cfg: SLDAConfig
     backend: str            # "jnp" | "pallas" | "pallas-interpret"
 
+    def __post_init__(self):
+        # the sparse draw has no compiled kernel (kernels/access.py
+        # `check_compiled_mode`); refuse the cell here, at plan time
+        if self.backend == "pallas" and self.cfg.sampler_mode == "sparse":
+            raise NotImplementedError(
+                "sampler_mode='sparse' has no compiled TPU kernel; use "
+                "sampler_mode='dense' or the jnp route (use_pallas=False)")
+
     # ---- routing (static)
 
     @property

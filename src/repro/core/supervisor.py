@@ -53,7 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.checkpoint import (CheckpointManager, latest_step, restore_chain)
+from repro.checkpoint import (TORN_CHECKPOINT_ERRORS, CheckpointManager,
+                              latest_step, restore_chain)
 from repro.metrics.ensemble import robust_z
 
 from . import combine
@@ -344,7 +345,7 @@ class ChainSupervisor:
             try:
                 chain_state = restore_chain(self.ckpt_dir, step, c, tmpl)
                 action = f"restart_from_step_{step}"
-            except Exception as e:  # noqa: BLE001 — corrupt file isolation
+            except TORN_CHECKPOINT_ERRORS as e:  # corrupt file isolation
                 events.append({"chain": c, "action": "checkpoint_corrupt",
                                "error": repr(e)})
         if chain_state is None:

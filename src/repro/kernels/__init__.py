@@ -9,8 +9,11 @@
   slda_train      — fused multi-sweep TRAINING launch: k sweeps per
                     launch with an in-kernel block-local delayed-count
                     refresh of the topic-word table (VMEM scratch,
-                    segmented one-hot matmul); chain-batched grid
+                    per-row ±1 adds); chain-batched grid
                     (M, blocks) runs all M chains in one launch
+  access          — the in-kernel reads and writes above, in forms the
+                    TPU compiler accepts (SMEM word ids + dynamic row
+                    loads, lane-select columns)
   flash_attention — blocked causal attention with native GQA index maps
   ssd_scan        — Mamba-2 chunked state-space scan (state in VMEM scratch)
   rmsnorm         — fused row-blocked RMSNorm

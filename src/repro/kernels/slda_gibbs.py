@@ -7,11 +7,12 @@ TPU adaptation (DESIGN.md §3): the token loop is inherently sequential, but
     dimension — documents are independent within a sweep because the
     topic-word table is sweep-frozen (AD-LDA delayed counts).
 
-Layout: the topic-word table is stored transposed, ``ntw_t [W, T]``, so the
-per-token access is a *row* gather (sublane-dim dynamic index), which the
-TPU supports natively; a column gather on the lane dim would not map.  The
-whole table lives in VMEM (sLDA vocabularies are small — the paper's is
-4238 phrases; W·T·4B ≈ 2 MB at T=128).
+Layout: the topic-word table is stored transposed, ``ntw_t [W, T]``, and
+lives whole in VMEM (sLDA vocabularies are small — the paper's is 4238
+phrases).  Per token, each document of the block reads its word's
+``[1, T]`` row with a dynamic sublane load addressed from an SMEM copy of
+the block's word ids (`access.gather_rows`; DESIGN.md §Predict-kernel,
+"Row access on the chip").
 
 Grid: (D / DOC_BLOCK,).  One grid cell sweeps DOC_BLOCK documents
 end-to-end and writes back their new assignments and doc-topic counts.
@@ -23,9 +24,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.mathutil import upper_tri_ones
-from .sparse import build_topic_index, sparse_two_stage_draw
+from .access import (check_compiled_mode, column, gather_rows, pick,
+                     set_column)
+from .sparse import (build_topic_index, gather_index_rows,
+                     sparse_two_stage_draw)
 
 
 def _gibbs_kernel(tokens_ref, mask_ref, unif_ref, z_ref, ndt_ref,
@@ -33,36 +38,39 @@ def _gibbs_kernel(tokens_ref, mask_ref, unif_ref, z_ref, ndt_ref,
                   alpha: float, beta: float, rho: float,
                   supervised: bool, n_tokens: int, vocab_size: int,
                   sampler_mode: str = "dense"):
-    # sparse mode appends the three sweep-frozen topic-index inputs;
-    # unpacking on the static mode keeps the dense trace byte-identical
+    # tokens_ref holds the block's word ids in SMEM (row addresses for
+    # gather_rows).  Sparse mode appends the three sweep-frozen
+    # topic-index inputs and runs interpreted only; unpacking on the
+    # static mode keeps the dense trace byte-identical
     if sampler_mode == "sparse":
-        idx_ref, vmask_ref, occm_ref, z_out_ref, ndt_out_ref = refs
+        idx_ref, vmask_ref, occm_ref, z_out_ref, ndt_out_ref, rows_ref = refs
     else:
-        z_out_ref, ndt_out_ref = refs
+        z_out_ref, ndt_out_ref, rows_ref = refs
     eta = eta_ref[0, :]                       # [T]
     nt = nt_ref[0, :]                         # [T]
-    ntw_t = ntw_t_ref[...]                    # [W, T] resident in VMEM
     y = y_ref[:, 0]                           # [DB]
     inv_len = invlen_ref[:, 0]                # [DB]
+    mask, unif, z = mask_ref[...], unif_ref[...], z_ref[...]   # [DB, N]
     T = eta.shape[0]
     topic_iota = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
     tri_u = upper_tri_ones(T)   # prefix-sum-as-matmul (see slda_predict.py)
 
     ndt0 = ndt_ref[...]                       # [DB, T]
     s0 = ndt0 @ eta                           # [DB]  running Σ_t η_t N_dt
+    z_out_ref[...] = z
 
     def token_step(n, carry):
         ndt, s = carry
-        w = tokens_ref[:, n]                  # [DB] int32 word ids
-        m = mask_ref[:, n]                    # [DB]
-        u = unif_ref[:, n]                    # [DB]
-        z_old = z_ref[:, n]                   # [DB]
+        m = column(mask, n)                   # [DB]
+        u = column(unif, n)                   # [DB]
+        z_old = column(z, n)                  # [DB]
 
-        old = (topic_iota == z_old[:, None]).astype(jnp.float32) * m[:, None]
+        own = topic_iota == z_old[:, None]
+        old = own.astype(jnp.float32) * m[:, None]
         ndt = ndt - old
-        s = s - jnp.take(eta, z_old) * m
+        s = s - pick(eta, own) * m
 
-        ntw_w = jnp.take(ntw_t, w, axis=0) - old        # [DB, T], -dn exact
+        ntw_w = gather_rows(ntw_t_ref, tokens_ref, n, rows_ref) - old
         logp = (jnp.log(ndt + alpha)
                 + jnp.log(ntw_w + beta)
                 - jnp.log(nt[None, :] - old + vocab_size * beta))
@@ -72,20 +80,20 @@ def _gibbs_kernel(tokens_ref, mask_ref, unif_ref, z_ref, ndt_ref,
 
         p = jnp.exp(logp - jnp.max(logp, axis=1, keepdims=True))
         if sampler_mode == "sparse":
+            w = column(tokens_ref[...], n)
             z_new = sparse_two_stage_draw(
-                p, u, jnp.take(idx_ref[...], w, axis=0),
-                jnp.take(vmask_ref[...], w, axis=0),
-                jnp.take(occm_ref[...], w, axis=0))
+                p, u, *gather_index_rows(w, idx_ref[...], vmask_ref[...],
+                                         occm_ref[...]))
         else:
             c = jnp.dot(p, tri_u)
             z_new = jnp.sum(
-                (c < (u * c[:, -1])[:, None]).astype(jnp.int32), axis=1)
+                (c < (u * c[:, T - 1])[:, None]).astype(jnp.int32), axis=1)
         z_new = jnp.where(m > 0, z_new, z_old).astype(jnp.int32)
 
-        new = (topic_iota == z_new[:, None]).astype(jnp.float32) * m[:, None]
-        ndt = ndt + new
-        s = s + jnp.take(eta, z_new) * m
-        z_out_ref[:, n] = z_new
+        new = topic_iota == z_new[:, None]
+        ndt = ndt + new.astype(jnp.float32) * m[:, None]
+        s = s + pick(eta, new) * m
+        set_column(z_out_ref, n, z_new)
         return ndt, s
 
     ndt, _ = jax.lax.fori_loop(0, n_tokens, token_step, (ndt0, s0))
@@ -104,6 +112,7 @@ def slda_gibbs_sweep_pallas(tokens, mask, uniforms, z, ndt, y, inv_len,
     draw against the per-word topic index of the sweep-frozen `ntw_t`
     (built here unless passed pre-built as `topic_index`).
     """
+    check_compiled_mode(sampler_mode, interpret)
     D, N = tokens.shape
     T = ndt.shape[-1]
     W = ntw_t.shape[0]
@@ -118,7 +127,9 @@ def slda_gibbs_sweep_pallas(tokens, mask, uniforms, z, ndt, y, inv_len,
         supervised=supervised, n_tokens=N, vocab_size=W,
         sampler_mode=sampler_mode)
 
-    in_specs = [doc_spec(N), doc_spec(N), doc_spec(N), doc_spec(N),
+    ids_spec = pl.BlockSpec((doc_block, N), lambda i: (i, 0),
+                            memory_space=pltpu.SMEM)
+    in_specs = [ids_spec, doc_spec(N), doc_spec(N), doc_spec(N),
                 doc_spec(T), doc_spec(1), doc_spec(1),
                 full((W, T)), full((1, T)), full((1, T))]
     operands = [tokens, mask, uniforms, z, ndt, y[:, None],
@@ -137,5 +148,6 @@ def slda_gibbs_sweep_pallas(tokens, mask, uniforms, z, ndt, y, inv_len,
         out_specs=[doc_spec(N), doc_spec(T)],
         out_shape=[jax.ShapeDtypeStruct((D, N), jnp.int32),
                    jax.ShapeDtypeStruct((D, T), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((doc_block, T), jnp.float32)],
         interpret=interpret,
     )(*operands)

@@ -10,9 +10,11 @@ into ONE kernel launch here (DESIGN.md §Predict-kernel).
 
 Three things make the fused kernel cheap:
 
-  * layout — φ̂ is stored transposed, ``phi_t [W, T]``, resident in VMEM,
-    so the per-token access is a sublane-dim *row* gather (the same trick
-    as the train kernel's ``ntw_t``);
+  * layout — φ̂ is stored transposed, ``phi_t [W, T]``, resident in VMEM;
+    per token each document loads its word's row, addressed from an SMEM
+    copy of the block's word ids (`access.gather_rows`, DESIGN.md
+    §Predict-kernel "Row access on the chip" — the same access as the
+    train kernel's ``ntw_t``);
   * no log/exp — prediction is unsupervised, p(z=t) ∝ (N_dt^{-dn}+α)·φ̂_tw,
     a product of positives, so the categorical is sampled from the plain
     product instead of a log-sum-exp (the Gaussian response term that
@@ -51,12 +53,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.mathutil import upper_tri_ones
-from .sparse import build_topic_index, sparse_two_stage_draw
+from .access import check_compiled_mode, column, gather_rows, set_column
+from .sparse import (build_topic_index, gather_index_rows,
+                     sparse_two_stage_draw)
 
-try:  # pltpu imports on CPU builds too; guard for exotic installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 # murmur3 finalizer constants (public domain, Austin Appleby)
 _MIX1 = np.uint32(0x85EBCA6B)
@@ -93,8 +94,14 @@ def counter_uniform(seed, ctr):
     x = (x ^ (x >> 16)) * _MIX1
     x = (x ^ (x >> 13)) * _MIX2
     x = x ^ (x >> 16)
-    # top 24 bits → f32 in [0, 1); strictly < 1 so inverse-CDF stays in range
-    return (x >> 8).astype(jnp.float32) * _INV24
+    return bits_to_uniform(x)
+
+
+def bits_to_uniform(x):
+    """Top 24 of 32 random bits → f32 in [0, 1), strictly < 1 so the
+    inverse CDF stays in range.  Goes through int32 (exact: x >> 8 <
+    2^24), because the TPU compiler has no uint32 → float32 cast."""
+    return (x >> 8).astype(jnp.int32).astype(jnp.float32) * _INV24
 
 
 def predict_uniforms(seeds, n_sweeps: int, n_tokens: int,
@@ -120,18 +127,19 @@ def _predict_kernel(tokens_ref, mask_ref, seed_ref, z_ref, ndt_ref, phi_t_ref,
                     *refs, alpha: float, n_burnin: int, n_samples: int,
                     n_tokens: int, ctr_stride: int, tpu_prng: bool,
                     chain_grid: bool = False, sampler_mode: str = "dense"):
-    # sparse mode appends the three per-word topic-index inputs (frozen
-    # like φ̂ itself); unpacking on the static mode keeps the dense trace
-    # byte-identical to every prior PR
+    # tokens_ref holds the doc block's word ids in SMEM (row addresses
+    # for gather_rows); phi_t_ref is the [W, T] table in VMEM.  Sparse
+    # mode appends the three per-word topic-index inputs (frozen like φ̂
+    # itself); it runs interpreted only (access.check_compiled_mode)
     if sampler_mode == "sparse":
-        idx_ref, vmask_ref, occm_ref, z_out_ref, avg_ref = refs
+        idx_ref, vmask_ref, occm_ref, z_out_ref, avg_ref, rows_ref = refs
     else:
-        z_out_ref, avg_ref = refs
-    phi_t = phi_t_ref[...]                    # [W, T] resident in VMEM
+        z_out_ref, avg_ref, rows_ref = refs
     seeds = seed_ref[:, 0]                    # [DB]
-    T = phi_t.shape[1]
+    T = phi_t_ref.shape[1]
     topic_iota = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
     tri_u = upper_tri_ones(T)
+    mask = mask_ref[...]                      # [DB, N]
 
     if tpu_prng:
         # one hardware stream per DOC BLOCK (the per-core PRNG is stateful,
@@ -156,34 +164,34 @@ def _predict_kernel(tokens_ref, mask_ref, seed_ref, z_ref, ndt_ref, phi_t_ref,
         ndt, acc = carry
 
         def token_step(n, ndt):
-            w = tokens_ref[:, n]              # [DB] int32 word ids
-            m = mask_ref[:, n]                # [DB]
-            z_old = z_out_ref[:, n]           # [DB]
+            m = column(mask, n)               # [DB]
+            z_old = column(z_out_ref[...], n)  # [DB]
             if tpu_prng:
-                bits = pltpu.bitcast(
-                    pltpu.prng_random_bits(w.shape), jnp.uint32)
-                u = (bits >> 8).astype(jnp.float32) * _INV24
+                u = bits_to_uniform(pltpu.bitcast(
+                    pltpu.prng_random_bits((m.shape[0], 1)),
+                    jnp.uint32))[:, 0]
             else:
                 u = counter_uniform(seeds, s * ctr_stride + n)
 
             old = (topic_iota == z_old[:, None]).astype(jnp.float32) * m[:, None]
             ndt = ndt - old
-            p = (ndt + alpha) * jnp.take(phi_t, w, axis=0)      # row gather
+            p = (ndt + alpha) * gather_rows(phi_t_ref, tokens_ref, n,
+                                            rows_ref)
             if sampler_mode == "sparse":
                 # two-stage sparse draw (rare stage-2 correction
                 # predicated inside — kernels/sparse.py)
+                w = column(tokens_ref[...], n)
                 z_new = sparse_two_stage_draw(
-                    p, u, jnp.take(idx_ref[...], w, axis=0),
-                    jnp.take(vmask_ref[...], w, axis=0),
-                    jnp.take(occm_ref[...], w, axis=0))
+                    p, u, *gather_index_rows(w, idx_ref[...],
+                                             vmask_ref[...], occm_ref[...]))
             else:
                 c = jnp.dot(p, tri_u)                           # prefix sums
                 z_new = jnp.sum(
-                    (c < (u * c[:, -1])[:, None]).astype(jnp.int32), axis=1)
+                    (c < (u * c[:, T - 1])[:, None]).astype(jnp.int32), axis=1)
             z_new = jnp.where(m > 0, z_new, z_old).astype(jnp.int32)
             ndt = ndt + (topic_iota == z_new[:, None]).astype(jnp.float32) \
                 * m[:, None]
-            z_out_ref[:, n] = z_new
+            set_column(z_out_ref, n, z_new)
             return ndt
 
         ndt = jax.lax.fori_loop(0, n_tokens, token_step, ndt)
@@ -210,6 +218,7 @@ def slda_predict_sweeps_pallas(tokens, mask, seeds, z0, ndt0, phi_t, *,
     doc_block (ops.py pads).  ctr_stride pins the PRNG counter stride
     (default N — see predict_uniforms).
     """
+    check_compiled_mode(sampler_mode, interpret)
     D, N = tokens.shape
     T = ndt0.shape[-1]
     W = phi_t.shape[0]
@@ -225,8 +234,10 @@ def slda_predict_sweeps_pallas(tokens, mask, seeds, z0, ndt0, phi_t, *,
         ctr_stride=int(N if ctr_stride is None else ctr_stride),
         tpu_prng=tpu_prng, sampler_mode=sampler_mode)
 
-    in_specs = [doc_spec(N), doc_spec(N), doc_spec(1),
-                doc_spec(N), doc_spec(T), full((W, T))]
+    ids_spec = pl.BlockSpec((doc_block, N), lambda i: (i, 0),
+                            memory_space=pltpu.SMEM)
+    in_specs = [ids_spec, doc_spec(N), doc_spec(1), doc_spec(N),
+                doc_spec(T), full((W, T))]
     operands = [tokens, mask, seeds[:, None], z0, ndt0, phi_t]
     if sampler_mode == "sparse":
         if topic_index is None:
@@ -242,6 +253,7 @@ def slda_predict_sweeps_pallas(tokens, mask, seeds, z0, ndt0, phi_t, *,
         out_specs=[doc_spec(N), doc_spec(T)],
         out_shape=[jax.ShapeDtypeStruct((D, N), jnp.int32),
                    jax.ShapeDtypeStruct((D, T), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((doc_block, T), jnp.float32)],
         interpret=interpret,
     )(*operands)
     return ndt_avg, z_final
@@ -271,6 +283,7 @@ def slda_predict_sweeps_chains_pallas(tokens, mask, seeds, z0, ndt0, phi_t,
     to its single-chain launch.
     Returns (ndt_avg [M, D, T], z_final [M, D, N]).
     """
+    check_compiled_mode(sampler_mode, interpret)
     D, N = tokens.shape
     M = phi_t.shape[0]
     T = ndt0.shape[-1]
@@ -291,8 +304,10 @@ def slda_predict_sweeps_chains_pallas(tokens, mask, seeds, z0, ndt0, phi_t,
         ctr_stride=int(N if ctr_stride is None else ctr_stride),
         tpu_prng=tpu_prng, chain_grid=True, sampler_mode=sampler_mode)
 
-    in_specs = [shared(N), shared(N), cdoc(1),
-                cdoc(N), cdoc(T), cfull((W, T))]
+    ids_spec = pl.BlockSpec((doc_block, N), lambda c, i: (i, 0),
+                            memory_space=pltpu.SMEM)
+    in_specs = [ids_spec, shared(N), cdoc(1), cdoc(N), cdoc(T),
+                cfull((W, T))]
     operands = [tokens, mask, seeds[..., None], z0, ndt0, phi_t]
     if sampler_mode == "sparse":
         if topic_index is None:
@@ -308,6 +323,7 @@ def slda_predict_sweeps_chains_pallas(tokens, mask, seeds, z0, ndt0, phi_t,
         out_specs=[cdoc(N), cdoc(T)],
         out_shape=[jax.ShapeDtypeStruct((M, D, N), jnp.int32),
                    jax.ShapeDtypeStruct((M, D, T), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((doc_block, T), jnp.float32)],
         interpret=interpret,
     )(*operands)
     return ndt_avg, z_final

@@ -12,7 +12,9 @@ What carries over from the predict kernel (DESIGN.md §Predict-kernel):
   * counter-hash PRNG — per-token uniforms from a murmur3-style mix of
     (doc_seed, sweep·N + n), shared bit-for-bit by kernel / jnp twin /
     oracle through `train_uniforms` (same contract as `predict_uniforms`);
-  * transposed `[W, T]` row-gather layout for the topic-word table;
+  * transposed `[W, T]` layout for the topic-word table, read one row
+    per document and token (DESIGN.md §Predict-kernel, "Row access on
+    the chip");
   * matmul prefix sums (`p @ U`, U upper-triangular ones) for the
     inverse-CDF categorical.
 
@@ -29,15 +31,14 @@ and applying the block's own ±1 deltas between the sweeps of one launch:
     its local copy — exact per block, delayed across blocks until the
     launch ends and the host applies the exact global
     `apply_count_deltas(z_launch_start, z_final)` refresh;
-  * the refresh is a **segmented one-hot matmul**: per token position the
-    block's ±1 topic deltas land on the local table through one
-    `[W, DB]·[DB, T]` contraction (an MXU op on TPU) instead of a
-    sequential per-document row-update loop; a `pl.when` skips the
-    contraction whenever no document in the block moved that token
-    (Magnusson et al.: late in sampling nearly all tokens are unchanged).
-    All products and partial sums are 0/±1 integers far below 2^24, so
-    the matmul totals are EXACT and bit-identical to the twin's and
-    oracle's scatter-adds regardless of accumulation order.
+  * the refresh adds each moved token's ±1 topic delta to its word's
+    table row, document by document (`access.add_rows`, the same SMEM
+    word ids and dynamic row access as the per-token reads); a
+    `pl.when` skips a token position whenever no document in the block
+    moved it (Magnusson et al.: late in sampling nearly all tokens are
+    unchanged).  All adds are 0/±1 integers far below 2^24, so the
+    totals are EXACT and bit-identical to the twin's and oracle's
+    scatter-adds regardless of order.
 
 **Sampling form** — two, selected by ``product_form``:
 
@@ -74,16 +75,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.mathutil import upper_tri_ones
-from .slda_predict import _GOLDEN, _INV24, _MIX1, _MIX2, counter_uniform
+from .access import (add_rows, check_compiled_mode, column, gather_rows,
+                     pick, set_column)
+from .slda_predict import (_GOLDEN, _MIX1, _MIX2, bits_to_uniform,
+                           counter_uniform)
 from .slda_predict import predict_uniforms as _uniforms_tensor
-from .sparse import build_topic_index, sparse_two_stage_draw
-
-try:  # pltpu imports on CPU builds too; guard for exotic installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from .sparse import (build_topic_index, gather_index_rows,
+                     sparse_two_stage_draw)
 
 
 def train_uniforms(seeds, n_sweeps: int, n_tokens: int,
@@ -102,21 +103,25 @@ def _train_kernel(tokens_ref, mask_ref, seed_ref, z_ref, ndt_ref, y_ref,
                   n_sweeps: int, n_tokens: int, ctr_stride: int,
                   vocab_size: int, tpu_prng: bool, product_form: bool,
                   chain_grid: bool, sampler_mode: str = "dense"):
-    # sparse mode appends three LAUNCH-frozen topic-index inputs (built
-    # by the wrapper from the entry table — in-launch count evolution
-    # never rebuilds them; exactness does not depend on index freshness).
-    # Unpacking on the static mode keeps the dense trace byte-identical.
+    # tokens_ref holds the block's word ids in SMEM: the row addresses of
+    # the per-token table reads and of the refresh's row adds; rows_ref
+    # is the [DB, T] staging buffer of both (access.py).  Sparse
+    # mode appends three LAUNCH-frozen topic-index inputs (built by the
+    # wrapper from the entry table — in-launch count evolution never
+    # rebuilds them; exactness does not depend on index freshness) and
+    # runs interpreted only.  Unpacking on the static mode keeps the
+    # dense trace byte-identical.
     if sampler_mode == "sparse":
         (idx_ref, vmask_ref, occm_ref,
-         z_out_ref, ndt_out_ref, ntw_scratch) = refs
+         z_out_ref, ndt_out_ref, ntw_scratch, rows_ref) = refs
     else:
-        z_out_ref, ndt_out_ref, ntw_scratch = refs
+        z_out_ref, ndt_out_ref, ntw_scratch, rows_ref = refs
     eta = eta_ref[0, :]                       # [T]
     seeds = seed_ref[:, 0]                    # [DB]
     y = y_ref[:, 0]                           # [DB]
     inv_len = invlen_ref[:, 0]                # [DB]
+    mask = mask_ref[...]                      # [DB, N]
     T = eta.shape[0]
-    DB = tokens_ref.shape[0]
     topic_iota = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
     tri_u = upper_tri_ones(T)
 
@@ -137,29 +142,30 @@ def _train_kernel(tokens_ref, mask_ref, seed_ref, z_ref, ndt_ref, y_ref,
     z_out_ref[...] = z_ref[...]               # z persists across sweeps
 
     def sweep_body(s, carry):
+        # ntw_scratch is frozen for the sweep: the refresh below runs
+        # after the token loop
         ndt_start, nt = carry                 # [DB, T], [T] sweep-frozen
-        ntw_t = ntw_scratch[...]              # frozen snapshot for the sweep
         z_prev = z_out_ref[...]               # [DB, N] sweep-start z
         s0 = ndt_start @ eta                  # [DB] running Σ_t η_t N_dt
 
         def token_step(n, carry2):
             ndt, st = carry2
-            w = tokens_ref[:, n]              # [DB] int32 word ids
-            m = mask_ref[:, n]                # [DB]
-            z_old = z_out_ref[:, n]           # [DB]
+            m = column(mask, n)               # [DB]
+            z_old = column(z_out_ref[...], n)  # [DB]
             if tpu_prng:
-                bits = pltpu.bitcast(
-                    pltpu.prng_random_bits(w.shape), jnp.uint32)
-                u = (bits >> 8).astype(jnp.float32) * _INV24
+                u = bits_to_uniform(pltpu.bitcast(
+                    pltpu.prng_random_bits((m.shape[0], 1)),
+                    jnp.uint32))[:, 0]
             else:
                 u = counter_uniform(seeds, s * ctr_stride + n)
 
-            old = (topic_iota == z_old[:, None]).astype(jnp.float32) \
-                * m[:, None]
+            own = topic_iota == z_old[:, None]
+            old = own.astype(jnp.float32) * m[:, None]
             ndt = ndt - old
-            st = st - jnp.take(eta, z_old) * m
+            st = st - pick(eta, own) * m
 
-            ntw_w = jnp.take(ntw_t, w, axis=0) - old    # [DB, T], -dn exact
+            ntw_w = gather_rows(ntw_scratch, tokens_ref, n, rows_ref) \
+                - old                                   # [DB, T], -dn exact
             if product_form:
                 p = (ndt + alpha) * (ntw_w + beta) \
                     / (nt[None, :] - old + vocab_size * beta)
@@ -180,53 +186,50 @@ def _train_kernel(tokens_ref, mask_ref, seed_ref, z_ref, ndt_ref, y_ref,
                 # two-stage sparse draw; the rare stage-2 correction is
                 # predicated inside (lax.cond — the value-returning form
                 # of pl.when, bitwise-equal to the branch-free select)
+                w = column(tokens_ref[...], n)
                 z_new = sparse_two_stage_draw(
-                    p, u, jnp.take(idx_ref[...], w, axis=0),
-                    jnp.take(vmask_ref[...], w, axis=0),
-                    jnp.take(occm_ref[...], w, axis=0))
+                    p, u, *gather_index_rows(w, idx_ref[...],
+                                             vmask_ref[...], occm_ref[...]))
             else:
                 c = jnp.dot(p, tri_u)                   # prefix sums
                 z_new = jnp.sum(
-                    (c < (u * c[:, -1])[:, None]).astype(jnp.int32), axis=1)
+                    (c < (u * c[:, T - 1])[:, None]).astype(jnp.int32),
+                    axis=1)
             z_new = jnp.where(m > 0, z_new, z_old).astype(jnp.int32)
 
-            ndt = ndt + (topic_iota == z_new[:, None]).astype(jnp.float32) \
-                * m[:, None]
-            st = st + jnp.take(eta, z_new) * m
-            z_out_ref[:, n] = z_new
+            new = topic_iota == z_new[:, None]
+            ndt = ndt + new.astype(jnp.float32) * m[:, None]
+            st = st + pick(eta, new) * m
+            set_column(z_out_ref, n, z_new)
             return ndt, st
 
         ndt, _ = jax.lax.fori_loop(0, n_tokens, token_step, (ndt_start, s0))
 
-        # block-local delayed-count refresh as a segmented one-hot matmul:
-        # for each token position the block's ±1 topic deltas reach the
-        # local table through one [W, DB]·[DB, T] contraction — 0/±1
-        # integer products with integer partial sums ≪ 2^24, so the totals
-        # are EXACT and order-independent (bit-identical to the twin's and
-        # oracle's scatter-adds).  Skipped after the final sweep (the
-        # local table is not an output) and — per token — whenever no
-        # document in the block moved (the common case late in sampling).
+        # block-local delayed-count refresh: for each token position the
+        # block's ±1 topic deltas are added to the table rows of the
+        # block's words, document by document (repeated words accumulate)
+        # — 0/±1 integer adds ≪ 2^24, so the totals are EXACT and
+        # order-independent (bit-identical to the twin's and oracle's
+        # scatter-adds).  Skipped after the final sweep (the local table
+        # is not an output) and — per token — whenever no document in
+        # the block moved (the common case late in sampling).
         @pl.when(s < n_sweeps - 1)
         def _refresh():
-            vocab_iota = jax.lax.broadcasted_iota(
-                jnp.int32, (vocab_size, DB), 0)
+            z_cur = z_out_ref[...]
 
             def refresh_token(n, _):
-                w = tokens_ref[:, n]
-                m = mask_ref[:, n]
-                zo = z_prev[:, n]
-                zn = z_out_ref[:, n]
-                moved = (zo != zn) & (m > 0)
+                zo = column(z_prev, n)
+                zn = column(z_cur, n)
+                moved = (zo != zn) & (column(mask, n) > 0)
 
                 @pl.when(jnp.any(moved))
-                def _mm():
+                def _rows():
                     mv = moved.astype(jnp.float32)            # [DB]
-                    sel = (vocab_iota == w[None, :]) \
-                        .astype(jnp.float32)                  # [W, DB]
-                    dvec = ((topic_iota == zn[:, None]).astype(jnp.float32)
-                            - (topic_iota == zo[:, None])
-                            .astype(jnp.float32)) * mv[:, None]  # [DB, T]
-                    ntw_scratch[...] = ntw_scratch[...] + jnp.dot(sel, dvec)
+                    rows_ref[...] = (
+                        (topic_iota == zn[:, None]).astype(jnp.float32)
+                        - (topic_iota == zo[:, None]).astype(jnp.float32)
+                    ) * mv[:, None]                           # [DB, T]
+                    add_rows(ntw_scratch, tokens_ref, n, rows_ref)
                 return 0
             jax.lax.fori_loop(0, n_tokens, refresh_token, 0)
 
@@ -258,6 +261,7 @@ def slda_train_sweeps_pallas(tokens, mask, seeds, z0, ndt0, y, inv_len,
     launch-frozen per-word topic index (built here from `ntw_t`, or
     passed pre-built as `topic_index=(idx, vmask, occm)`).
     """
+    check_compiled_mode(sampler_mode, interpret)
     D, N = tokens.shape
     T = ndt0.shape[-1]
     W = ntw_t.shape[0]
@@ -274,7 +278,9 @@ def slda_train_sweeps_pallas(tokens, mask, seeds, z0, ndt0, y, inv_len,
         vocab_size=W, tpu_prng=tpu_prng, product_form=product_form,
         chain_grid=False, sampler_mode=sampler_mode)
 
-    in_specs = [doc_spec(N), doc_spec(N), doc_spec(1), doc_spec(N),
+    ids_spec = pl.BlockSpec((doc_block, N), lambda i: (i, 0),
+                            memory_space=pltpu.SMEM)
+    in_specs = [ids_spec, doc_spec(N), doc_spec(1), doc_spec(N),
                 doc_spec(T), doc_spec(1), doc_spec(1),
                 full((W, T)), full((1, T)), full((1, T))]
     operands = [tokens, mask, seeds[:, None], z0, ndt0, y[:, None],
@@ -293,7 +299,8 @@ def slda_train_sweeps_pallas(tokens, mask, seeds, z0, ndt0, y, inv_len,
         out_specs=[doc_spec(N), doc_spec(T)],
         out_shape=[jax.ShapeDtypeStruct((D, N), jnp.int32),
                    jax.ShapeDtypeStruct((D, T), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((W, T), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((W, T), jnp.float32),
+                        pltpu.VMEM((doc_block, T), jnp.float32)],
         interpret=interpret,
     )(*operands)
 
@@ -315,6 +322,7 @@ def slda_train_sweeps_chains_pallas(tokens, mask, seeds, z0, ndt0, y,
     same order, bit-identical per chain to the single-chain launch.
     Returns (z_final [M, D, N], ndt_final [M, D, T]).
     """
+    check_compiled_mode(sampler_mode, interpret)
     M, D, N = tokens.shape
     T = ndt0.shape[-1]
     W = ntw_t.shape[1]
@@ -333,9 +341,10 @@ def slda_train_sweeps_chains_pallas(tokens, mask, seeds, z0, ndt0, y,
         vocab_size=W, tpu_prng=tpu_prng, product_form=product_form,
         chain_grid=True, sampler_mode=sampler_mode)
 
-    in_specs = [cdoc(N), cdoc(N), cdoc(1), cdoc(N),
-                cdoc(T), cdoc(1), cdoc(1),
-                cfull((W, T)), cfull((1, T)), cfull((1, T))]
+    ids_spec = pl.BlockSpec((None, doc_block, N), lambda c, i: (c, i, 0),
+                            memory_space=pltpu.SMEM)
+    in_specs = [ids_spec, cdoc(N), cdoc(1), cdoc(N), cdoc(T), cdoc(1),
+                cdoc(1), cfull((W, T)), cfull((1, T)), cfull((1, T))]
     operands = [tokens, mask, seeds[..., None], z0, ndt0, y[..., None],
                 inv_len[..., None], ntw_t, nt[:, None, :], eta[:, None, :]]
     if sampler_mode == "sparse":
@@ -352,7 +361,8 @@ def slda_train_sweeps_chains_pallas(tokens, mask, seeds, z0, ndt0, y,
         out_specs=[cdoc(N), cdoc(T)],
         out_shape=[jax.ShapeDtypeStruct((M, D, N), jnp.int32),
                    jax.ShapeDtypeStruct((M, D, T), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((W, T), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((W, T), jnp.float32),
+                        pltpu.VMEM((doc_block, T), jnp.float32)],
         interpret=interpret,
     )(*operands)
 
@@ -370,8 +380,8 @@ def slda_train_sweeps_jnp(tokens, mask, seeds, z0, ndt0, y, inv_len,
     vector op per token, identical op order to the kernel so the bits
     match), the token scan unrolled ×8, and the block-local between-sweep
     refresh as a scalar 2-scatter over the block's tokens (same exact
-    integer arithmetic as the kernel's segmented one-hot matmul, so the
-    tables agree bit-for-bit regardless of accumulation order).
+    integer arithmetic as the kernel's row adds, so the tables agree
+    bit-for-bit regardless of accumulation order).
 
     In product form (the multi-sweep default) the per-token work is one
     row gather + one `exp`, mirroring the kernel verbatim.  The log form

@@ -66,9 +66,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.checkpoint import (AsyncCheckpointManager, CheckpointManager,
-                              read_manifest, restore_chain,
-                              restore_elastic, save_checkpoint)
+from repro.checkpoint import (TORN_CHECKPOINT_ERRORS, AsyncCheckpointManager,
+                              CheckpointManager, read_manifest,
+                              restore_chain, restore_elastic,
+                              save_checkpoint)
 from repro.core.supervisor import ChainSupervisor, F_KILLED, F_STRAGGLER
 from repro.core.types import GibbsState, SLDAConfig, partition
 from repro.core.plan import build_schedule
@@ -324,7 +325,7 @@ class ElasticRunner:
             rewind = int(extra.get("progress", [0] * (c + 1))[c])
             events.append({"chain": c, "action":
                            f"restore_step_{durable}_progress_{rewind}"})
-        except Exception as e:  # noqa: BLE001 — torn file is fault-isolated
+        except TORN_CHECKPOINT_ERRORS as e:  # torn file is fault-isolated
             bk["epoch"][c] += 1
             rewind = 0
             keys = jax.vmap(

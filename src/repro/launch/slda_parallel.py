@@ -24,7 +24,6 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import (Corpus, SLDAConfig, build_schedule, combine,
@@ -114,11 +113,11 @@ def parallel_slda_shard_map(key, train: Corpus, test: Corpus,
         return (yhat_all.reshape(m, yhat.shape[-1]),
                 stats_all.reshape(m, 2))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         chain_fn, mesh=mesh,
         in_specs=(P(), shard_spec, test_spec),
         out_specs=(P(), P()),
-        check_rep=False,   # chain-local scans carry unvarying state
+        check_vma=False,   # chain-local scans carry unvarying state
     )
     yhat_all, stats_all = fn(key, shards, test)
     if alive is None and auto_quarantine:

@@ -82,13 +82,13 @@ import dataclasses
 import hashlib
 import math
 import time
-import zipfile
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.checkpoint import latest_step, restore_checkpoint
+from repro.checkpoint import (TORN_CHECKPOINT_ERRORS, latest_step,
+                              restore_checkpoint)
 from repro.core.combine import median, simple_average, weighted_average
 from repro.core.plan import as_bucketed, build_plan
 from repro.core.supervisor import (MODEL_FAULTS, F_NAN_YHAT,
@@ -709,8 +709,7 @@ class SLDAPredictionService:
         try:
             models, manifest = restore_checkpoint(
                 ckpt_dir, step, self.models)
-        except (FileNotFoundError, KeyError, ValueError, OSError,
-                zipfile.BadZipFile) as e:   # truncated .npz = torn write
+        except TORN_CHECKPOINT_ERRORS as e:
             return _reject(f"{type(e).__name__}: {e}")
         quarantined = []
         if self.svc.robust_checks:

@@ -234,3 +234,16 @@ def test_weighted_average_end_to_end_bitwise_padded_vs_bucketed():
     y_bkt = run_weighted_average(key, _train, _test, cfg_bkt, 4)
     np.testing.assert_allclose(np.asarray(y_pad), np.asarray(y_bkt),
                                atol=0)
+
+
+def test_pallas_route_off_tpu_is_interpreted():
+    """Off a TPU, `use_pallas=True` resolves to the interpreted route
+    and the kernel wrappers interpret: the compiled route is only ever
+    taken where every device is a TPU."""
+    from repro.kernels import ops
+    assert jax.default_backend() != "tpu"
+    cfg = dataclasses.replace(CFG, use_pallas=True)
+    assert cfg.resolve_backend() == "pallas-interpret"
+    assert cfg.resolve_backend(jax.devices()) == "pallas-interpret"
+    assert ops._interpret()
+    assert CFG.resolve_backend() == "jnp"

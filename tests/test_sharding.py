@@ -14,12 +14,12 @@ from repro.models import init_params
 
 
 def mesh_single():
-    # AbstractMesh takes a shape_tuple of (name, size) pairs
-    return AbstractMesh((("data", 16), ("model", 16)))
+    # AbstractMesh takes (axis_sizes, axis_names)
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def mesh_multi():
-    return AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_chain_axes_mapping():
@@ -42,7 +42,7 @@ def test_dp_axes_complement():
 def test_param_specs_cover_tree_and_divide(name):
     """Every param leaf gets a spec whose sharded dims divide evenly."""
     cfg = SMOKES[name]
-    mesh = AbstractMesh((("data", 4), ("model", 4)))
+    mesh = AbstractMesh((4, 4), ("data", "model"))
     params = jax.eval_shape(
         lambda k: init_params(k, cfg, 4), jax.ShapeDtypeStruct((2,),
                                                                jnp.uint32))

@@ -32,8 +32,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (SLDAConfig, bucket_corpus, counts_from_assignments,
-                        partition, topic_occupancy_index)
+from repro.core import (SLDAConfig, bucket_corpus, build_plan,
+                        counts_from_assignments, partition,
+                        topic_occupancy_index)
 from repro.core.parallel import train_chains_keyed
 from repro.data import make_slda_corpus, train_test_split
 from repro.kernels import ops, ref
@@ -352,3 +353,16 @@ def test_sparse_property_occupancy_and_chain_batching(m, cap, conc, data):
                      .at[d_idx, z].add(mm))(z_c, mask)
     np.testing.assert_allclose(np.asarray(ndt_c), np.asarray(ndt_r),
                                atol=0)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas-interpret", "pallas"])
+def test_sparse_plan_refused_on_compiled_route(backend):
+    """The sparse draw has no compiled TPU kernel: a sparse plan on the
+    compiled pallas route fails at plan time (never runs interpreted);
+    the jnp and interpret routes keep it."""
+    shards = partition(_train, 2)
+    if backend == "pallas":
+        with pytest.raises(NotImplementedError, match="sparse"):
+            build_plan(shards, _CFG, backend)
+    else:
+        assert build_plan(shards, _CFG, backend).backend == backend
