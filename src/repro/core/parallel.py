@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from . import combine
 from .gibbs import train_chain
@@ -150,21 +151,29 @@ def run_weighted_average(key, train: Corpus, test: Corpus, cfg: SLDAConfig,
     the paper reports Weighted Average as the slowest algorithm.  With
     `cfg.fuse_weighted_predict` (the default) the test and train passes
     run as ONE chain-batched fused pass over the concatenated corpus —
-    same sweeps per document, half the sequential token-loop launches."""
+    same sweeps per document, half the sequential token-loop launches.
+
+    Host spans `slda.fit.schedule`, `slda.fit.train`, `slda.fit.predict`
+    and `slda.fit.combine` name each phase in a profiler trace."""
     k1, k2, k3 = jax.random.split(key, 3)
-    models = _train_chains_jit(k1, build_schedule(partition(train, m), cfg),
-                               cfg)
-    if cfg.fuse_weighted_predict:
-        both = _concat_corpora(test, train)
-        yhat = _predict_chains_jit(k2, models, build_schedule(both, cfg),
-                                   cfg)
-        yhat_te, yhat_tr = yhat[:, :test.n_docs], yhat[:, test.n_docs:]
-    else:
-        yhat_te = _predict_chains_jit(k2, models,
-                                      build_schedule(test, cfg), cfg)
-        yhat_tr = _predict_chains_jit(k3, models,
-                                      build_schedule(train, cfg), cfg)
-    return _combine_weighted(yhat_te, yhat_tr, train.y, cfg, alive)
+    with TraceAnnotation("slda.fit.schedule"):
+        shards = build_schedule(partition(train, m), cfg)
+        if cfg.fuse_weighted_predict:
+            both = build_schedule(_concat_corpora(test, train), cfg)
+        else:
+            test_s = build_schedule(test, cfg)
+            train_s = build_schedule(train, cfg)
+    with TraceAnnotation("slda.fit.train"):
+        models = _train_chains_jit(k1, shards, cfg)
+    with TraceAnnotation("slda.fit.predict"):
+        if cfg.fuse_weighted_predict:
+            yhat = _predict_chains_jit(k2, models, both, cfg)
+            yhat_te, yhat_tr = yhat[:, :test.n_docs], yhat[:, test.n_docs:]
+        else:
+            yhat_te = _predict_chains_jit(k2, models, test_s, cfg)
+            yhat_tr = _predict_chains_jit(k3, models, train_s, cfg)
+    with TraceAnnotation("slda.fit.combine"):
+        return _combine_weighted(yhat_te, yhat_tr, train.y, cfg, alive)
 
 
 ALGORITHMS = {

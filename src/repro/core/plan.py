@@ -263,15 +263,16 @@ class ExecutionPlan:
         bc, cfg = self.corpus, self.cfg
 
         def rebuild(_):
-            ntw2, pieces = 0.0, []
-            for b, zb in zip(bc.buckets, z_new_b):
-                nd, nw, _ = jax.vmap(
-                    lambda t, m_, zz: counts_from_assignments(
-                        t, m_, zz, cfg.n_topics, cfg.vocab_size))(
-                    b.tokens, b.mask, zb)
-                pieces.append(nd)
-                ntw2 = ntw2 + nw
-            return bc.merge_docs(pieces), ntw2, jnp.sum(ntw2, axis=-1)
+            with jax.named_scope("rebuild"):
+                ntw2, pieces = 0.0, []
+                for b, zb in zip(bc.buckets, z_new_b):
+                    nd, nw, _ = jax.vmap(
+                        lambda t, m_, zz: counts_from_assignments(
+                            t, m_, zz, cfg.n_topics, cfg.vocab_size))(
+                        b.tokens, b.mask, zb)
+                    pieces.append(nd)
+                    ntw2 = ntw2 + nw
+                return bc.merge_docs(pieces), ntw2, jnp.sum(ntw2, axis=-1)
 
         def incremental(_):
             ntw2, nt2 = state.ntw, state.nt
@@ -280,16 +281,18 @@ class ExecutionPlan:
                     ntw2, nt2, b.tokens, b.mask, zo, zn)
             return ndt, ntw2, nt2
 
-        if isinstance(rebuild_now, bool):
-            ndt, ntw, nt = rebuild(None) if rebuild_now else \
-                incremental(None)
-        else:
-            ndt, ntw, nt = jax.lax.cond(rebuild_now, rebuild, incremental,
-                                        None)
-        lengths = jnp.maximum(bc.lengths(), 1.0)
-        eta = jax.vmap(lambda nd, l, yy: solve_eta(nd / l[:, None], yy,
-                                                   self.cfg))(
-            ndt, lengths, bc.y)
+        with jax.named_scope("count_refresh"):
+            if isinstance(rebuild_now, bool):
+                ndt, ntw, nt = rebuild(None) if rebuild_now else \
+                    incremental(None)
+            else:
+                ndt, ntw, nt = jax.lax.cond(rebuild_now, rebuild,
+                                            incremental, None)
+        with jax.named_scope("eta_solve"):
+            lengths = jnp.maximum(bc.lengths(), 1.0)
+            eta = jax.vmap(lambda nd, l, yy: solve_eta(nd / l[:, None], yy,
+                                                       self.cfg))(
+                ndt, lengths, bc.y)
         return GibbsState(z=tuple(z_new_b), ndt=ndt, ntw=ntw, nt=nt,
                           eta=eta)
 
@@ -324,9 +327,10 @@ class ExecutionPlan:
             pieces.append(nd2)
         return z_new_b, bc.merge_docs(pieces)
 
-    def _blocks_launch(self, state, ks, it, n_sweeps, inv_len_b):
+    def _blocks_launch(self, state, ks, n_sweeps, inv_len_b):
         """One fused multi-sweep launch per bucket (chain grids intact,
-        PRNG counter stride pinned to the source max_len) + EM boundary."""
+        PRNG counter stride pinned to the source max_len).  Returns the
+        launch's (z_new_b, ndt) for the EM boundary."""
         from repro.kernels import ops   # local import (DESIGN.md §1)
         bc, cfg = self.corpus, self.cfg
         d_m, S = bc.perm.shape[-1], bc.ctr_stride
@@ -348,9 +352,7 @@ class ExecutionPlan:
                 sparse_topic_cap=cfg.sparse_topic_cap)
             z_new_b.append(z2)
             pieces.append(nd2)
-        rebuild_now = self._rebuild_now(it)
-        return self._refresh_and_solve(z_new_b, bc.merge_docs(pieces),
-                                       state, rebuild_now)
+        return z_new_b, bc.merge_docs(pieces)
 
     def _stair_staging(self):
         """Schedule-invariant staging of the stair trainer — the folded
@@ -377,14 +379,15 @@ class ExecutionPlan:
                  for b in bc.buckets], axis=1)),
         )
 
-    def _stair_launch(self, state, ks, it, n_sweeps, staging):
+    def _stair_launch(self, state, ks, n_sweeps, staging):
         """One STAIRCASE fused launch runs all in-launch sweeps for ALL
         chains (jnp route, multi-bucket): chains folded doc-major around
         a stacked [M·W, T] table, bucket widths walked as token-range
         segments over the live doc suffix — per-sweep step count stays
         N_max while slots collapse to the staircase.  The in-launch
         delayed-count partition is the WHOLE corpus (doc_block→D limit
-        of the fused family)."""
+        of the fused family).  Returns the launch's (z_new_b, ndt) for
+        the EM boundary."""
         from repro.kernels.slda_train import slda_train_stair_jnp
         bc, cfg = self.corpus, self.cfg
         M = bc.n_chains
@@ -408,9 +411,7 @@ class ExecutionPlan:
             sampler_mode=cfg.sampler_mode,
             sparse_topic_cap=cfg.sparse_topic_cap)
         z_new_b = _unstair_segments(bc, [unfold(z) for z in z_segs_f])
-        ndt = unsort(unfold(ndt_f))
-        return self._refresh_and_solve(z_new_b, ndt, state,
-                                       self._rebuild_now(it))
+        return z_new_b, unsort(unfold(ndt_f))
 
     def _rebuild_now(self, it):
         every = self.cfg.count_rebuild_every
@@ -449,7 +450,8 @@ class ExecutionPlan:
             def em_step(carry, inp):
                 state, status = carry
                 ks, it = inp
-                z_new_b, ndt = self._seed_sweep(state, ks, inv_len_b)
+                with jax.named_scope("gibbs_sweep"):
+                    z_new_b, ndt = self._seed_sweep(state, ks, inv_len_b)
                 state = self._refresh_and_solve(
                     z_new_b, ndt, state, self._rebuild_now(it))
                 if em_hook is not None:
@@ -466,11 +468,17 @@ class ExecutionPlan:
         # schedule-invariant staging is hoisted HERE, once per trace —
         # the launch closures see it as scan constants
         if self.executor == "stair":
-            launch = functools.partial(self._stair_launch,
+            sweeps = functools.partial(self._stair_launch,
                                        staging=self._stair_staging())
         else:
-            launch = functools.partial(self._blocks_launch,
+            sweeps = functools.partial(self._blocks_launch,
                                        inv_len_b=self._inv_len_b())
+
+        def launch(state, ks, it, n_sweeps):
+            with jax.named_scope("gibbs_sweep"):
+                z_new_b, ndt = sweeps(state, ks, n_sweeps)
+            return self._refresh_and_solve(z_new_b, ndt, state,
+                                           self._rebuild_now(it))
         keys = jnp.moveaxis(jax.vmap(lambda k: jax.random.split(
             k, n_full + (1 if rem else 0)))(k_sweeps), 0, 1)
 
@@ -621,7 +629,8 @@ class ExecutionPlan:
             k, (D,), 0, jnp.iinfo(jnp.int32).max, jnp.int32))(ks[:, 1])
         run = (self._predict_stair if self.executor == "stair"
                else self._predict_blocks)
-        ndt_avg = run(models.phi, z0, seeds)            # [M, D, T] orig
+        with jax.named_scope("predict_sweeps"):
+            ndt_avg = run(models.phi, z0, seeds)        # [M, D, T] orig
         lengths = jnp.maximum(bc.lengths(), 1.0)
         return jax.vmap(lambda nd: nd / lengths[:, None])(ndt_avg)
 

@@ -621,11 +621,12 @@ def apply_count_deltas(ntw: Array, nt: Array, tokens: Array, mask: Array,
     cap = int(min(cap, total))
 
     def dense(_):
-        ntw2 = (ntw.at[z_old, tokens].add(-changed)
-                .at[z_new, tokens].add(changed))
-        nt2 = (nt + jnp.zeros_like(nt).at[z_new].add(changed)
-               - jnp.zeros_like(nt).at[z_old].add(changed))
-        return ntw2, nt2
+        with jax.named_scope("dense"):
+            ntw2 = (ntw.at[z_old, tokens].add(-changed)
+                    .at[z_new, tokens].add(changed))
+            nt2 = (nt + jnp.zeros_like(nt).at[z_new].add(changed)
+                   - jnp.zeros_like(nt).at[z_old].add(changed))
+            return ntw2, nt2
 
     if cap <= 0 or cap >= total:
         return dense(None)
@@ -633,16 +634,17 @@ def apply_count_deltas(ntw: Array, nt: Array, tokens: Array, mask: Array,
     n_changed = jnp.sum(flat > 0)
     w_all, zo_all, zn_all = tokens.ravel(), z_old.ravel(), z_new.ravel()
 
-    def sparse(_):
-        idx = jnp.nonzero(flat > 0, size=cap, fill_value=0)[0]
-        wt = (jnp.arange(cap) < n_changed).astype(ntw.dtype)
-        w, zo, zn = w_all[idx], zo_all[idx], zn_all[idx]
-        ntw2 = ntw.at[zo, w].add(-wt).at[zn, w].add(wt)
-        nt2 = (nt + jnp.zeros_like(nt).at[zn].add(wt)
-               - jnp.zeros_like(nt).at[zo].add(wt))
-        return ntw2, nt2
+    def compact(_):
+        with jax.named_scope("compact"):
+            idx = jnp.nonzero(flat > 0, size=cap, fill_value=0)[0]
+            wt = (jnp.arange(cap) < n_changed).astype(ntw.dtype)
+            w, zo, zn = w_all[idx], zo_all[idx], zn_all[idx]
+            ntw2 = ntw.at[zo, w].add(-wt).at[zn, w].add(wt)
+            nt2 = (nt + jnp.zeros_like(nt).at[zn].add(wt)
+                   - jnp.zeros_like(nt).at[zo].add(wt))
+            return ntw2, nt2
 
-    return jax.lax.cond(n_changed <= cap, sparse, dense, None)
+    return jax.lax.cond(n_changed <= cap, compact, dense, None)
 
 
 def topic_occupancy_index(table_t: Array, cap: int):

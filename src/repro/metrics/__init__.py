@@ -1,6 +1,6 @@
 """Metrics substrate: step logging + chain-ensemble health."""
-from .log import MetricLogger, throughput_tokens_per_s
+from .log import MetricLogger
 from .ensemble import chain_divergence, ensemble_health, robust_z
 
-__all__ = ["MetricLogger", "throughput_tokens_per_s", "chain_divergence",
-           "ensemble_health", "robust_z"]
+__all__ = ["MetricLogger", "chain_divergence", "ensemble_health",
+           "robust_z"]

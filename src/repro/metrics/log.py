@@ -40,8 +40,3 @@ class MetricLogger:
                     continue           # trailing partial line after a crash
                 rows[rec["step"]] = rec     # later lines supersede
         return [rows[s] for s in sorted(rows)]
-
-
-def throughput_tokens_per_s(global_batch: int, seq_len: int,
-                            step_seconds: float) -> float:
-    return global_batch * seq_len / max(step_seconds, 1e-9)

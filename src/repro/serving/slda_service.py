@@ -396,7 +396,15 @@ class SLDAPredictionService:
         latency budget from now (falls back to
         `svc.default_deadline_s`; 0/None = no deadline); a request
         whose deadline lapses before dispatch resolves to a typed
-        `STATUS_EXPIRED` Result instead of occupying a slot."""
+        `STATUS_EXPIRED` Result instead of occupying a slot.
+
+        Runs inside the host span `slda.serve.submit` (argument
+        `req_id`); an auto-flush is a child span."""
+        with jax.profiler.TraceAnnotation("slda.serve.submit",
+                                          req_id=self._next_id):
+            return self._submit(tokens, deadline_s)
+
+    def _submit(self, tokens, deadline_s):
         toks = np.asarray(tokens, np.int32).ravel()
         if toks.size < 1:
             self._stats["rejected_invalid"] += 1
@@ -540,9 +548,10 @@ class SLDAPredictionService:
         def dispatch(keys, models, plan, chain_weights):
             counts[plan_key] += 1           # fires once per trace
             zb = plan.predict_zbar(keys, models)      # [M, D, T]
-            yhat = jax.vmap(lambda z, e: z @ e)(zb, models.eta)
-            comb = _combine_yhat(rule, yhat, chain_weights,
-                                 models.train_mse)
+            with jax.named_scope("combine"):
+                yhat = jax.vmap(lambda z, e: z @ e)(zb, models.eta)
+                comb = _combine_yhat(rule, yhat, chain_weights,
+                                     models.train_mse)
             return zb, yhat, comb
 
         fn = jax.jit(dispatch)
@@ -563,21 +572,39 @@ class SLDAPredictionService:
     def flush(self):
         """Dispatch one micro-batch from the pending queue (no-op when
         empty).  Returns the req_ids completed by this batch (shed ids
-        resolve through `result()`, not this list)."""
+        resolve through `result()`, not this list).
+
+        A dispatch runs as three host spans, each with the batch index
+        `batch`: `slda.serve.pack` (packing, schedule, plan and keys,
+        host→device copies included; also `docs`, the documents
+        placed), `slda.serve.device` (the compiled dispatch through
+        `block_until_ready`) and `slda.serve.publish` (`_publish`).  An
+        empty queue records none."""
         if not self._pending:
             return []
-        placed, n = self._pack()
-        if n == 0:      # every pending request expired — nothing to run
-            return []
-        bc, meta = self._build_schedule(placed)
-        plan = build_plan(bc, self.cfg, self.backend)
-        fn = self._dispatch_fn(plan.cache_key())
-        keys = jax.random.split(
-            jax.random.fold_in(self.key, self._batches), self.n_chains)
+        batch = self._batches
+        with jax.profiler.TraceAnnotation("slda.serve.pack",
+                                          batch=batch) as span:
+            placed, n = self._pack()
+            span.set_metadata(docs=n)
+            if n == 0:  # every pending request expired — nothing to run
+                return []
+            bc, meta = self._build_schedule(placed)
+            plan = build_plan(bc, self.cfg, self.backend)
+            fn = self._dispatch_fn(plan.cache_key())
+            keys = jax.random.split(
+                jax.random.fold_in(self.key, batch), self.n_chains)
         self._batches += 1
-        zb, yhat, comb = fn(keys, self.models, plan, self.chain_weights)
-        jax.block_until_ready(comb)
+        with jax.profiler.TraceAnnotation("slda.serve.device", batch=batch):
+            zb, yhat, comb = fn(keys, self.models, plan, self.chain_weights)
+            jax.block_until_ready(comb)
         t_done = self._clock()
+        with jax.profiler.TraceAnnotation("slda.serve.publish", batch=batch):
+            return self._publish(bc, meta, n, zb, yhat, comb, t_done)
+
+    def _publish(self, bc, meta, n, zb, yhat, comb, t_done):
+        """Copy one dispatch's outputs to the host, screen them, and
+        resolve its requests (results and result cache)."""
         zb, yhat, comb = np.asarray(zb), np.asarray(yhat), np.asarray(comb)
         real = [d for d, slot in enumerate(meta) if slot is not None]
         if self.svc.robust_checks and real:
@@ -748,7 +775,6 @@ class SLDAPredictionService:
         return {
             "traces": int(sum(self._trace_counts.values())),
             "compiled_plans": len(self._plan_cache),
-            "plan_cache_keys": len(self._plan_cache),
             # the active per-token draw mode — part of every plan cache
             # key (cfg is in ExecutionPlan.cache_key()), so switching it
             # allocates a DISTINCT jitted callable (test_slda_serving)
@@ -762,7 +788,6 @@ class SLDAPredictionService:
                 / (slot_total if self._stats["dispatches"] else 1), 4),
             "result_cache_hits": int(self._stats["cache_hits"]),
             "result_cache_size": len(self._result_cache),
-            "pending": len(self._pending),
             "width_ladder": list(self.svc.width_ladder),
             "slot_quota": list(self.svc.slot_quota),
             "bucketed": self.svc.bucketed,
