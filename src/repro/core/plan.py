@@ -20,8 +20,8 @@ Magnusson et al.; Yan et al., Towards Big Topic Modeling) from the
   * the plan owns all routing: executor ("blocks" per-bucket fused
     launches on the pallas route and for 1-bucket jnp, "stair" stacked
     twins for multi-bucket jnp), the sweeps-per-launch schedule
-    (n_full full launches + one remainder), and the count-refresh
-    cadence.
+    (n_full full launches + one remainder).  The count refresh at
+    every EM boundary is one exact recount.
 
 Exactness contract (tests/test_dispatch_matrix.py): at
 sweeps_per_launch=1 every cell is bit-identical per document to the
@@ -43,8 +43,8 @@ import numpy as np
 from .regression import solve_eta
 from .types import (BucketedCorpus, Corpus, GibbsState, SLDAConfig,
                     SLDAModel, _stair_segments, _take_docs,
-                    _unstair_segments, apply_count_deltas, bucket_corpus,
-                    bucket_signature, counts_from_assignments)
+                    _unstair_segments, bucket_corpus, bucket_signature,
+                    counts_from_assignments)
 
 
 # ------------------------------------------------------- canonicalization
@@ -215,10 +215,7 @@ class ExecutionPlan:
             "sweeps_per_launch": spl,
             "launches": n_full + (1 if rem else 0),
             "remainder_sweeps": rem,
-            "count_refresh": ("rebuild every "
-                              f"{cfg.count_rebuild_every} launches"
-                              if cfg.count_rebuild_every > 0
-                              else "incremental deltas only"),
+            "count_refresh": "exact recount every EM boundary",
             "slot_tokens_per_sweep": int(slot),
             "real_tokens_per_sweep": int(real),
             "padded_slot_frac": round(1.0 - real / max(src_slots, 1), 4),
@@ -256,38 +253,21 @@ class ExecutionPlan:
                            nt=jnp.sum(ntw, axis=-1), eta=eta)
         return state, z_fill
 
-    def _refresh_and_solve(self, z_new_b, ndt, state, rebuild_now):
+    def _refresh_and_solve(self, z_new_b, ndt):
         """THE EM boundary (the one copy): exact global count refresh —
-        full rebuild or incremental (z_old, z_new) deltas, both exact —
-        then the per-chain η ridge solve on ORIGINAL-order rows."""
+        one recount of ntw from the new assignments, nt = ntw.sum(-1),
+        ndt as the sweep left it (exact) — then the per-chain η ridge
+        solve on ORIGINAL-order rows."""
         bc, cfg = self.corpus, self.cfg
-
-        def rebuild(_):
-            with jax.named_scope("rebuild"):
-                ntw2, pieces = 0.0, []
-                for b, zb in zip(bc.buckets, z_new_b):
-                    nd, nw, _ = jax.vmap(
-                        lambda t, m_, zz: counts_from_assignments(
-                            t, m_, zz, cfg.n_topics, cfg.vocab_size))(
-                        b.tokens, b.mask, zb)
-                    pieces.append(nd)
-                    ntw2 = ntw2 + nw
-                return bc.merge_docs(pieces), ntw2, jnp.sum(ntw2, axis=-1)
-
-        def incremental(_):
-            ntw2, nt2 = state.ntw, state.nt
-            for b, zo, zn in zip(bc.buckets, state.z, z_new_b):
-                ntw2, nt2 = jax.vmap(apply_count_deltas)(
-                    ntw2, nt2, b.tokens, b.mask, zo, zn)
-            return ndt, ntw2, nt2
-
-        with jax.named_scope("count_refresh"):
-            if isinstance(rebuild_now, bool):
-                ndt, ntw, nt = rebuild(None) if rebuild_now else \
-                    incremental(None)
-            else:
-                ndt, ntw, nt = jax.lax.cond(rebuild_now, rebuild,
-                                            incremental, None)
+        scatter = jax.vmap(lambda nw, t, m_, zz: nw.at[zz, t].add(m_))
+        with jax.named_scope("count_refresh"), jax.named_scope("rebuild"):
+            # one chain-batched scatter per bucket into one table:
+            # integer adds, exact in any order
+            ntw = jnp.zeros((bc.n_chains, cfg.n_topics, cfg.vocab_size),
+                            jnp.float32)
+            for b, zb in zip(bc.buckets, z_new_b):
+                ntw = scatter(ntw, b.tokens, b.mask, zb)
+            nt = jnp.sum(ntw, axis=-1)
         with jax.named_scope("eta_solve"):
             lengths = jnp.maximum(bc.lengths(), 1.0)
             eta = jax.vmap(lambda nd, l, yy: solve_eta(nd / l[:, None], yy,
@@ -413,10 +393,6 @@ class ExecutionPlan:
         z_new_b = _unstair_segments(bc, [unfold(z) for z in z_segs_f])
         return z_new_b, unsort(unfold(ndt_f))
 
-    def _rebuild_now(self, it):
-        every = self.cfg.count_rebuild_every
-        return (it % every == 0) if every > 0 else False
-
     def n_boundaries(self) -> int:
         """EM boundaries this plan executes (count refresh + η solve
         points): one per sweep at spl=1, one per launch at spl>1 —
@@ -438,11 +414,10 @@ class ExecutionPlan:
         into `status` (initialised from `status0`), all with zero extra
         host syncs; the accumulated status surfaces only in the return
         value `(state, status)`.  `it` is the EM-boundary index (sweep
-        index at spl=1, launch index at spl>1) plus `it_offset`, which
-        also offsets the count-rebuild cadence so a supervisor running
-        the loop round-by-round keeps the single-run cadence.  With
-        `em_hook=None` the loop is byte-for-byte the pre-hook program
-        and returns `state` alone."""
+        index at spl=1, launch index at spl>1) plus `it_offset`, so a
+        supervisor running the loop round-by-round sees the single-run
+        boundary indices.  With `em_hook=None` the loop is byte-for-byte
+        the pre-hook program and returns `state` alone."""
         spl, n_full, rem = self.sweep_schedule()
         if spl == 1:
             inv_len_b = self._inv_len_b()   # hoisted: scan constant
@@ -452,8 +427,7 @@ class ExecutionPlan:
                 ks, it = inp
                 with jax.named_scope("gibbs_sweep"):
                     z_new_b, ndt = self._seed_sweep(state, ks, inv_len_b)
-                state = self._refresh_and_solve(
-                    z_new_b, ndt, state, self._rebuild_now(it))
+                state = self._refresh_and_solve(z_new_b, ndt)
                 if em_hook is not None:
                     state, status = em_hook(state, it, status)
                 return (state, status), None
@@ -474,17 +448,16 @@ class ExecutionPlan:
             sweeps = functools.partial(self._blocks_launch,
                                        inv_len_b=self._inv_len_b())
 
-        def launch(state, ks, it, n_sweeps):
+        def launch(state, ks, n_sweeps):
             with jax.named_scope("gibbs_sweep"):
                 z_new_b, ndt = sweeps(state, ks, n_sweeps)
-            return self._refresh_and_solve(z_new_b, ndt, state,
-                                           self._rebuild_now(it))
+            return self._refresh_and_solve(z_new_b, ndt)
         keys = jnp.moveaxis(jax.vmap(lambda k: jax.random.split(
             k, n_full + (1 if rem else 0)))(k_sweeps), 0, 1)
 
         def launch_step(carry, inp):
             state, status = carry
-            state = launch(state, inp[0], inp[1], spl)
+            state = launch(state, inp[0], spl)
             if em_hook is not None:
                 state, status = em_hook(state, inp[1], status)
             return (state, status), None
@@ -496,7 +469,7 @@ class ExecutionPlan:
                 (keys[:n_full], jnp.arange(n_full) + it_offset))
         if rem:
             it = jnp.asarray(n_full) + it_offset
-            state = launch(state, keys[-1], it, rem)
+            state = launch(state, keys[-1], rem)
             if em_hook is not None:
                 state, status = em_hook(state, it, status)
         return state if em_hook is None else (state, status)

@@ -47,16 +47,15 @@ class SLDAConfig:
     n_pred_samples: int = 10 # test-time sweeps averaged for z̄
     use_pallas: bool = False # route sweeps through the slda TPU kernels
     pred_doc_block: int = 8  # doc block of the fused prediction kernel
-    count_rebuild_every: int = 16  # exact ntw/nt rebuild cadence during
-                             # training: iterations in between apply exact
-                             # (z_old, z_new) delta updates instead of the
-                             # full scatter; the periodic rebuild bounds
-                             # float32 accumulation drift.  0 = never
-                             # rebuild, 1 = rebuild every sweep (seed
-                             # behaviour).  Cadence counts LAUNCHES when
-                             # sweeps_per_launch > 1.  Either refresh form
-                             # is exact, so this knob is perf-only
-                             # (BENCH_slda_train.json records the sweep).
+    count_rebuild_every: int = 16  # exact ntw/nt rebuild cadence of the
+                             # delta refresh (`apply_count_deltas`) in the
+                             # CPU benchmarks' loops: iterations in between
+                             # apply exact (z_old, z_new) delta updates
+                             # instead of the full scatter.  0 = never
+                             # rebuild, 1 = rebuild every sweep.  The plan
+                             # (train_em) does not read it: it recounts
+                             # ntw at every EM boundary.  Either refresh
+                             # form is exact, so this knob is perf-only.
     sweeps_per_launch: int = 1  # training Gibbs sweeps fused into one
                              # kernel launch / scan body.  1 = seed
                              # semantics (threefry uniforms, η solve every
@@ -595,6 +594,10 @@ def apply_count_deltas(ntw: Array, nt: Array, tokens: Array, mask: Array,
                        z_old: Array, z_new: Array, cap: int | None = None):
     """Exact incremental (ntw, nt) refresh from one sweep's reassignments.
 
+    The plan's EM boundary does not call this: it recounts ntw from the
+    new assignments (`ExecutionPlan._refresh_and_solve`).  It serves
+    `core.gibbs.sweep(exact_rebuild=False)` and the CPU benchmarks.
+
     Only tokens whose topic actually changed carry weight (typically few,
     late in sampling — Magnusson et al., sparse partially collapsed
     samplers), so the scatter is issued in **changed-token compaction**
@@ -603,15 +606,14 @@ def apply_count_deltas(ntw: Array, nt: Array, tokens: Array, mask: Array,
     dense [D·N]-index 2-scatter that is mostly zero-weight no-ops.  If a
     sweep reassigns more than `cap` tokens (early sweeps), a `lax.cond`
     falls back to the dense form — exactness never depends on the cap.
+    Under `jax.vmap` that predicate is batched, so both forms run.
 
-    cap=None picks the backend's measured winner: max(128, D·N/8) slots
-    where scatter cost scales with the index count (TPU/GPU), the dense
-    form on CPU — on XLA:CPU the nonzero+gather overhead makes the
-    compacted branch ~3× a dense scatter even at 5 % change
-    (DESIGN.md §Train-kernel).  Pass `cap=0` to force dense, or an
-    explicit slot count to force compaction.  Counts stay exact either
-    way: ±1.0 float32 updates are lossless below 2^24, and
-    `SLDAConfig.count_rebuild_every` bounds drift beyond that.
+    cap=None picks the dense form on CPU, where the nonzero+gather
+    overhead makes the compacted branch ~3× a dense scatter even at 5 %
+    change (DESIGN.md §Train-kernel), and max(128, D·N/8) slots on other
+    backends (never measured as a winner there).  Pass `cap=0` to force
+    dense, or an explicit slot count to force compaction.  Counts stay
+    exact either way: ±1.0 float32 updates are lossless below 2^24.
     """
     changed = mask * (z_new != z_old).astype(mask.dtype)
     flat = changed.ravel()

@@ -118,9 +118,9 @@ def slda_train_sweeps(tokens, mask, z0, ndt0, y, inv_len, ntw, nt, eta,
     internal kernel detail); seeds: int32 [D] per-document PRNG seeds.
     Returns (z_final [D, N], ndt_final [D, T]).  The topic-word table
     refreshes *block-locally* between the launch's sweeps (delayed counts
-    across blocks, DESIGN.md §Train-kernel) — the caller applies the
-    exact global refresh from (z0, z_final) afterwards, e.g. via
-    `core.types.apply_count_deltas`.  At n_sweeps=1 the launch is exactly
+    across blocks, DESIGN.md §Train-kernel) — the caller refreshes the
+    exact global tables afterwards (the plan recounts them from
+    z_final).  At n_sweeps=1 the launch is exactly
     one seed-semantics sweep (keep product_form=False there to preserve
     the seed sampling bits).
 

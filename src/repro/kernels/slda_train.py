@@ -29,8 +29,8 @@ and applying the block's own ±1 deltas between the sweeps of one launch:
     exactly the seed semantics);
   * between sweeps each `doc_block` applies ITS OWN documents' deltas to
     its local copy — exact per block, delayed across blocks until the
-    launch ends and the host applies the exact global
-    `apply_count_deltas(z_launch_start, z_final)` refresh;
+    launch ends and the plan recounts the exact global tables from
+    z_final;
   * the refresh adds each moved token's ±1 topic delta to its word's
     table row, document by document (`access.add_rows`, the same SMEM
     word ids and dynamic row access as the per-token reads); a

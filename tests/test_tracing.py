@@ -18,8 +18,7 @@ from repro.serving import ServiceConfig, SLDAPredictionService
 
 CFG = SLDAConfig(n_topics=4, vocab_size=24, n_iters=2, rho=0.25,
                  n_pred_burnin=1, n_pred_samples=1, count_rebuild_every=2)
-TRAIN_SCOPES = {"gibbs_sweep", "count_refresh", "rebuild", "dense",
-                "eta_solve"}
+TRAIN_SCOPES = {"gibbs_sweep", "count_refresh", "rebuild", "eta_solve"}
 
 
 def _corpus(d=16, n=12, seed=0):
@@ -72,6 +71,36 @@ def test_programs_carry_their_scopes(spl, buckets, compiled):
         jax.random.PRNGKey(0), _models(), build_schedule(corpus, cfg), cfg)
     assert "predict_sweeps" in scopes_of(
         low.as_text(dialect="hlo", debug_info=True))
+
+
+def _ops_under(hlo_text: str, scope: str) -> list:
+    """Opcodes of the instructions whose op_name path holds `scope`."""
+    ops = []
+    for line in hlo_text.splitlines():
+        path = re.search(r'op_name="([^"]*)"', line)
+        op = re.search(r"=\s+(?:\([^)]*\)|\S+)\s+([a-z][\w-]*)\(", line)
+        if path and op and scope in scopes_of(path.group(0)):
+            ops.append(op.group(1))
+    return ops
+
+
+@pytest.mark.parametrize("spl,buckets", [(1, 0), (2, 0), (2, 2)],
+                         ids=["seed", "blocks", "stair"])
+def test_count_refresh_is_one_scatter_per_bucket(spl, buckets):
+    """The refresh is one recount: a scatter per bucket and no branch —
+    no compacted or dense delta form beside it, nothing chosen at run
+    time.  The configurations have no remainder launch, so the program
+    traces the EM boundary once."""
+    cfg = dataclasses.replace(CFG, sweeps_per_launch=spl,
+                              length_buckets=buckets)
+    assert cfg.n_iters % spl == 0
+    shards = build_schedule(partition(_corpus(), 2), cfg)
+    text = jax.jit(train_chains, static_argnums=(2,)).lower(
+        jax.random.PRNGKey(0), shards, cfg).as_text(dialect="hlo",
+                                                     debug_info=True)
+    ops = _ops_under(text, "count_refresh")
+    assert ops.count("scatter") == len(shards.buckets)
+    assert "conditional" not in ops
 
 
 def test_count_delta_branches_are_scoped():
