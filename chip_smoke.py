@@ -18,11 +18,14 @@ train / 1216 test, M=8 chains), corpus generated from `--seed`:
   4. test documents served through `SLDAPredictionService` built from
      those models, against the offline plan path.
 
-`--chips 4` runs `launch.slda_parallel.parallel_slda_shard_map` with
-M=8 chains on a 4-device mesh (2 per device) and on a 1-device mesh
-(8 per device) in this one process, and checks that the combined ŷ is
-equal, that the 4-device program holds no collective but the final
-`all_gather`s, and that each device holds its own chains.
+`--chips 4` runs `launch.slda_parallel.parallel_slda_shard_map`'s
+Weighted Average with M=8 chains on a 4-device mesh (2 per device) and
+on a 1-device mesh (8 per device) in this one process, reads each fit
+through `return_chains=True`, and checks that the combined ŷ is equal,
+that the 4-device program holds no collective but the one `all_gather`,
+that each device holds its own chains, and that each chain's φ̂ comes
+from whole counts adding up to its training shard (bench/checks.py
+`count_gap`).
 
 Every phase prints one JSON line.  A failed check exits non-zero after
 the remaining phases ran; off a TPU the script exits at once.  The last
@@ -236,10 +239,13 @@ def one_chip(sm: Smoke, seed: int, sizes: dict, expect: str):
 
 
 def four_chips(sm: Smoke, seed: int, sizes: dict, devices):
-    """parallel_slda_shard_map, M chains over 4 devices vs 1 device."""
+    """parallel_slda_shard_map's Weighted Average, M chains over 4 devices
+    vs 1 device, read through its `return_chains` seam."""
+    from bench.checks import count_gap, word_counts
     cfg = SLDA_MDNA
     m = sizes["n_chains"]
     train, test = make_data(seed, cfg, sizes)
+    wc = word_counts(train.tokens, train.mask, m, cfg.vocab_size)
     mesh4 = Mesh(np.array(devices[:4]), ("data",))
     mesh1 = Mesh(np.array(devices[:1]), ("data",))
     key = jax.random.PRNGKey(5)
@@ -247,33 +253,41 @@ def four_chips(sm: Smoke, seed: int, sizes: dict, devices):
     for name, mesh in (("4dev", mesh4), ("1dev", mesh1)):
         n_dev = mesh.devices.size
         cpd = m // n_dev
-        # each device holds the training documents of its own chains
-        # (partition() reshapes [D] → [M, D/M], chain-major)
-        tr = jax.device_put(train, NamedSharding(mesh, P("data")))
+        # every device holds the whole training and test sets: its chains
+        # train on their own shards and predict test ‖ train
+        tr = jax.device_put(train, NamedSharding(mesh, P()))
         te = jax.device_put(test, NamedSharding(mesh, P()))
         run = functools.partial(parallel_slda_shard_map, cfg=cfg, mesh=mesh,
-                                rule="weighted", chains_per_device=cpd)
-        yhat, c_s, r_s, hlo = compile_and_run(run, key, tr, te)
-        shards = tr.tokens.addressable_shards
-        held = sorted((s.device.id, s.data.shape[0]) for s in shards)
+                                rule="weighted", chains_per_device=cpd,
+                                return_chains=True)
+        fit, c_s, r_s, hlo = compile_and_run(run, key, tr, te)
+        held = sorted((s.device.id, s.data.shape[0])
+                      for s in fit.models.phi.addressable_shards)
         per_dev_shape = f"s32[{cpd},{train.n_docs // m},{train.max_len}]"
         kinds = collective_kinds(hlo)
-        mse = float(jnp.mean((yhat - test.y) ** 2))
+        gap = count_gap(fit.models.phi, wc, cfg.beta)
+        mse = float(jnp.mean((fit.yhat - test.y) ** 2))
         emit(f"shard_map_{name}", devices=n_dev, chains_per_device=cpd,
              compile_s=c_s, run_s=r_s, test_mse=mse,
              r2=1.0 - mse / float(jnp.var(test.y)), collectives=kinds,
-             train_docs_held=held, per_device_shard_in_hlo=per_dev_shape
-             in hlo, tpu_custom_call="tpu_custom_call" in hlo)
+             chains_held=held, count_gap=gap,
+             yhat_chains_shape=list(fit.yhat_chains.shape),
+             per_device_shard_in_hlo=per_dev_shape in hlo,
+             tpu_custom_call="tpu_custom_call" in hlo)
         sm.check("tpu_custom_call" in hlo, f"{name}: no tpu_custom_call")
         sm.check(per_dev_shape in hlo,
                  f"{name}: per-device chain shard {per_dev_shape} not in HLO")
         sm.check(len({d for d, _ in held}) == n_dev
-                 and all(r == train.n_docs // n_dev for _, r in held),
-                 f"{name}: training shards not spread {held}")
+                 and all(r == cpd for _, r in held),
+                 f"{name}: chains not spread {held}")
+        sm.check(gap <= 3e-3, f"{name}: chain φ̂ counts off their shards "
+                 f"by {gap}")
+        sm.check(fit.yhat_chains.shape == (m, test.n_docs + train.n_docs),
+                 f"{name}: per-chain ŷ {fit.yhat_chains.shape}")
         if n_dev > 1:
-            sm.check(set(kinds) <= {"all-gather"} and kinds,
-                     f"{name}: collectives {kinds} beyond the all_gathers")
-        out[name] = np.asarray(yhat)
+            sm.check(kinds == {"all-gather": 1},
+                     f"{name}: collectives {kinds}, not the one all_gather")
+        out[name] = np.asarray(fit.yhat)
     diff = float(np.max(np.abs(out["4dev"] - out["1dev"])))
     equal = bool(np.array_equal(out["4dev"], out["1dev"]))
     emit("shard_map_layouts", equal=equal, max_abs_diff=diff)

@@ -134,3 +134,38 @@ def test_sparse_mode_is_refused_when_compiled(one_chip):
     fn, args = _case("predict_chains", one_chip, w, n)
     with pytest.raises(NotImplementedError, match="sparse"):
         jax.jit(functools.partial(fn, sampler_mode="sparse")).lower(*args)
+
+
+def test_shard_runner_compiles_for_four_v5e(topo, monkeypatch):
+    """The multi-device runner's Weighted Average program at the MD&A
+    cell's size on a described 2x2 mesh, 2 chains a chip, the Pallas
+    kernels compiled: the one collective is the all-gather."""
+    import dataclasses
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import SLDA_MDNA
+    from repro.core import Corpus
+    from repro.kernels import ops
+    from repro.launch import slda_parallel
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:4]), ("chains",))
+    rep = NamedSharding(mesh, P())
+    w, n = WIDTHS["mdna"]
+    d_tr, d_te = 3000, 1216
+    cfg = dataclasses.replace(SLDA_MDNA, use_pallas=True)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
+    corpus = lambda *lead: Corpus(sds(lead + (n,), jnp.int32),
+                                  sds(lead + (n,), jnp.float32),
+                                  sds(lead, jnp.float32))
+    hlo = slda_parallel._shard_chains_jit.lower(
+        sds((2,), jnp.uint32), corpus(M, d_tr // M), corpus(d_te + d_tr),
+        sds((d_tr,), jnp.float32), None, cfg=cfg, mesh=mesh, axis="chains",
+        cpd=M // 4, rule="weighted", n_test=d_te,
+        quarantine=True).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert re.findall(r"\s((?:all-gather|all-reduce|all-to-all|"
+                      r"collective-permute|reduce-scatter)(?:-start)?)\(",
+                      hlo) == ["all-gather"]
